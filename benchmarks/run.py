@@ -1,15 +1,20 @@
 """Benchmark harness: one entry per paper table/figure + the roofline.
 
 Prints ``name,value,derived`` CSV rows after each bench's own report.
+A bench that raises fails the run.  These are CPU benches of the smoke
+preset (no device numbers)::
+
+    JAX_PLATFORMS=cpu PYTHONPATH=src:. python benchmarks/run.py
 """
 
 from __future__ import annotations
 
 from repro.launch.mesh import simulate_host_devices
 
-# the serve bench's tensor-parallel sweep needs a simulated device mesh,
-# and XLA freezes the host device count at the first computation — so
-# the split must happen before ANY bench touches a device
+# the serve bench's tensor-parallel sweep needs a 4-device mesh; on the
+# CPU that is a simulated split, and XLA freezes the host device count at
+# the first computation — so the split must happen before ANY bench
+# touches a device
 simulate_host_devices(4)
 
 
@@ -125,14 +130,15 @@ def main() -> None:
     )
 
     print("=" * 72)
-    try:
+    if roofline.load_cells():
         rf = roofline.main()
         hist = rf["dominant_histogram"]
         for term, count in sorted(hist.items()):
             rows.append((f"roofline_cells_dominated_by_{term}", count,
                          f"of {rf['cells_single']}"))
-    except Exception as e:  # dry-run artifacts absent
-        print(f"  roofline skipped: {e}")
+    else:
+        print(f"  roofline skipped: no dry-run artifacts in "
+              f"{roofline.DRYRUN_DIR}")
 
     print("=" * 72)
     print("name,value,derived")

@@ -37,9 +37,10 @@ from typing import Dict, List, Optional
 
 from repro.launch.mesh import make_serving_mesh, simulate_host_devices
 
-# before the first computation: split the host CPU into 4 simulated XLA
-# devices so the shard sweep has a mesh to run on (a no-op if XLA_FLAGS
-# already pins a device count — e.g. under the test conftest)
+# before the first computation: under JAX_PLATFORMS=cpu, split the host
+# CPU into 4 simulated XLA devices so the shard sweep has a mesh to run
+# on (a no-op if XLA_FLAGS already pins a device count — e.g. under the
+# test conftest)
 simulate_host_devices(4)
 
 import jax  # noqa: E402

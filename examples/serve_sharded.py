@@ -1,7 +1,8 @@
 """Sharded serving: tensor-parallel decode + data-parallel replicas.
 
-Splits the host CPU into 4 simulated XLA devices, then demos the two
-sharding planes:
+On the CPU (``JAX_PLATFORMS=cpu``) splits the host into 4 simulated
+XLA devices; on a four-chip TPU host the chips are the mesh.  Demos the
+two sharding planes:
 
 1. **Tensor parallel** — one engine whose attention params and KV page
    pool are sharded over a 4-device ``("model",)`` mesh; every decode
@@ -16,7 +17,7 @@ sharding planes:
    keyed by (seed, token index), so re-homed streams stay byte-identical
    to an undisturbed run.
 
-    PYTHONPATH=src python examples/serve_sharded.py
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/serve_sharded.py
 """
 
 import dataclasses
@@ -24,7 +25,7 @@ import dataclasses
 from repro.launch.mesh import make_serving_mesh, simulate_host_devices
 
 # must run before the first computation: XLA reads the device-count
-# flag once, at backend initialization
+# flag once, at backend initialization (CPU backend only)
 simulate_host_devices(4)
 
 import jax  # noqa: E402

@@ -1,4 +1,4 @@
-"""Dispatching wrapper: Pallas on TPU, interpret-mode elsewhere.
+"""Dispatching wrapper: compiled Pallas on TPU, interpret mode on CPU.
 
 ``flash_attention`` accepts the model-side layout (B, S, K, G, hd) used by
 ``repro.models.attention`` and returns the same layout.
@@ -6,9 +6,9 @@
 
 from __future__ import annotations
 
-
-import jax
 import jax.numpy as jnp
+
+from repro.kernels import resolve_interpret
 
 from .flash_attention import flash_attention_pallas
 
@@ -29,11 +29,10 @@ def flash_attention(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     B, Sq, K, G, hd = qg.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     q = qg.reshape(B, Sq, K * G, hd)
     out = flash_attention_pallas(
         q, k, v, jnp.asarray(window, jnp.int32),
-        scale=scale, logit_cap=logit_cap, causal=causal, interpret=interpret,
+        scale=scale, logit_cap=logit_cap, causal=causal,
+        interpret=resolve_interpret(interpret),
     )
     return out.reshape(B, Sq, K, G, hd)

@@ -11,6 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
+
 from .paged_attention import paged_attention_pallas
 
 __all__ = ["paged_attention", "paged_attention_sharded"]
@@ -18,12 +20,10 @@ __all__ = ["paged_attention", "paged_attention_sharded"]
 
 def paged_attention(q, k_pages, v_pages, page_table, lens, *, scale,
                     interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return paged_attention_pallas(
         q, k_pages, v_pages,
         jnp.asarray(page_table, jnp.int32), jnp.asarray(lens, jnp.int32),
-        scale=scale, interpret=interpret,
+        scale=scale, interpret=resolve_interpret(interpret),
     )
 
 
@@ -49,15 +49,13 @@ def paged_attention_sharded(q, k_pages, v_pages, page_table, lens, *,
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     def local(q_l, kp_l, vp_l, table, lens_):
         return paged_attention(q_l, kp_l, vp_l, table, lens_,
                                scale=scale, interpret=interpret)
 
     rep = P()
-    return shard_map(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P(None, axis_name, None), P(None, None, axis_name, None),
                   P(None, None, axis_name, None), rep, rep),
         out_specs=P(None, axis_name, None),

@@ -12,8 +12,8 @@ Grid ``(B, max_pages)``: each step fetches one physical page and serves
 **every** query head of that sequence from it — the earlier
 ``(B, K·G, max_pages)`` layout re-fetched the same page once per query
 head, multiplying both the DMA traffic on TPU and the grid-iteration
-overhead in interpret mode (the serving engine decodes through this
-kernel in interpret mode on CPU CI, so grid size is wall-clock there).
+overhead in interpret mode (CPU tests decode through this kernel in
+interpret mode, so grid size is wall-clock there; a TPU runs it compiled).
 Per-page online softmax lives in VMEM scratch shaped ``(K, G[, hd])``.
 Invalid pages (table entry < 0, or beyond the sequence length) are
 masked; their DMA reads page 0 (clamped index) and discards the result.

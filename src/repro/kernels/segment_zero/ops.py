@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-import jax
+from repro.kernels import resolve_interpret
 
 from .segment_zero import segment_zero_pallas
 
@@ -10,6 +10,5 @@ __all__ = ["segment_zero"]
 
 
 def segment_zero(x, lo, hi, *, interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return segment_zero_pallas(x, lo, hi, interpret=interpret)
+    return segment_zero_pallas(x, lo, hi,
+                               interpret=resolve_interpret(interpret))

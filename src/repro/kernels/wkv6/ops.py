@@ -2,8 +2,9 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
+
+from repro.kernels import resolve_interpret
 
 from .wkv6 import wkv6_pallas
 
@@ -11,7 +12,6 @@ __all__ = ["wkv6"]
 
 
 def wkv6(r, k, v, w, u, state0, *, interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return wkv6_pallas(r, k, v, w, u,
-                       state0.astype(jnp.float32), interpret=interpret)
+                       state0.astype(jnp.float32),
+                       interpret=resolve_interpret(interpret))

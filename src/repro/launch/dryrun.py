@@ -217,8 +217,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Path,
 
     # ---- analyses -----------------------------------------------------------
     try:
-        from repro.compat import cost_analysis as _ca
-        cost = _ca(compiled)
+        cost = compiled.cost_analysis() or {}
     except Exception as e:  # pragma: no cover
         cost = {"error": str(e)}
     try:

@@ -1,8 +1,8 @@
 """Production mesh construction.
 
 ``make_production_mesh`` is a function (not a module-level constant) so that
-importing this module never touches JAX device state — smoke tests see one
-CPU device; only ``dryrun.py`` forces 512 host devices.
+importing this module never touches JAX device state — the CPU tests see a
+4-way split of the host; only ``dryrun.py`` forces 512 host devices.
 
 Topology: one v5e pod = 256 chips arranged ``(data=16, model=16)``; the
 multi-pod mesh adds a leading pure-DP ``pod`` axis (DCN between pods, ICI
@@ -40,8 +40,11 @@ def simulate_host_devices(n: int = 4) -> None:
     first computation (importing jax is fine; using a device is not).
     A pre-existing device-count flag is respected, so nesting harnesses
     (conftest → bench → example) never fight over the count.
+
+    The flag splits only the CPU backend and never picks the platform:
+    run with ``JAX_PLATFORMS=cpu`` to get the split, and on a TPU host
+    the real chips are the devices.
     """
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -63,8 +66,8 @@ def make_serving_mesh(devices: Optional[int] = None, *, offset: int = 0):
     if offset + n > len(avail):
         raise ValueError(
             f"need devices [{offset}, {offset + n}) but only "
-            f"{len(avail)} exist — call simulate_host_devices() before "
-            "the first jax computation"
+            f"{len(avail)} exist — on the CPU, call "
+            "simulate_host_devices() before the first jax computation"
         )
     return jax.sharding.Mesh(avail[offset:offset + n], (SERVING_AXIS,))
 

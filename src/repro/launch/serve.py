@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import get_config, get_reduced, list_archs
 from repro.core.tasks import TenantQuota
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.runtime import Request, Server, ServerConfig
 
@@ -86,9 +87,12 @@ def main() -> None:
                          "run to run (and across chaos evictions)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # jitted, so each weight is drawn and cast to its dtype in one fused
+    # pass instead of materialising a full-width f32 copy first
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     tenants = [t.strip() for t in args.tenant.split(",") if t.strip()] \
         or ["serving"]
     quotas = (

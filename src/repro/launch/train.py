@@ -21,6 +21,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_reduced, list_archs
 from repro.core.gofer import Gofer
 from repro.data import DataConfig, Loader, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model, mesh_context
 from repro.optim import ScheduleConfig
@@ -47,6 +48,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = build_model(cfg)
     mesh = make_host_mesh()

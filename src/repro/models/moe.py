@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from .common import ArchConfig, constrain, current_mesh, gated_mlp
 
 __all__ = ["moe_params_shape", "init_moe_params", "moe_block"]
@@ -213,7 +211,7 @@ def moe_block(
         xb = constrain(x, b_axes, s_axis, None)
         e_spec = P(model_axis, None, data_axis)
         d_spec = P(model_axis, data_axis, None)
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(b_axes or None, s_axis, None), P(None, None),

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 __all__ = ["quantize_int8", "dequantize_int8", "compressed_psum",
            "maybe_psum", "ErrorFeedback", "compressed_grad_tree"]
 
@@ -124,7 +122,7 @@ def compressed_grad_tree(grads, mesh, axis_name: str = "pod"):
         def fn(gl):
             return compressed_psum(gl, axis_name)
 
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=mesh,
             in_specs=P(*([None] * g.ndim)),
             out_specs=P(*([None] * g.ndim)),

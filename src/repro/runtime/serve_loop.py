@@ -278,46 +278,52 @@ class ServingEngine:
                     "kv_mode='paged' needs a PagedKVAllocator with a "
                     "bounded pool (pool_pages) to size the device pages"
                 )
-            store = model.init_paged_state(
-                self.kv.pool_pages, self.kv.tokens_per_page
+            def init_store():
+                return model.init_paged_state(
+                    self.kv.pool_pages, self.kv.tokens_per_page
+                )
+
+            self._decode_paged = jax.jit(
+                model.paged_decode_step, donate_argnums=(1,)
             )
-            if self.mesh is not None:
-                # tensor-parallel decode: params and every physical page
-                # shard over the mesh per the model's TP specs (the page
-                # *pool* is per-device — each member holds its head/d
-                # slice of every page), and the decode step runs under
-                # shard_map so the paged-attention kernel grid sees only
-                # local heads; the model body psums the logits.  Prefill
-                # / scatter / COW stay plain jit: GSPMD reads the same
-                # sharded buffers, and exactness is the model's contract
-                # (integer ToyLM: bit-exact; transformers: per-head
-                # attention is untouched, only the wo psum reorders
-                # float adds).
+            if self.mesh is None:
+                store = init_store()
+            else:
+                # the mesh's devices hold the params and every physical
+                # page, per the model's TP specs (the page *pool* is
+                # per-device — each member holds its head/d slice of
+                # every page); the pool is built in place, never on
+                # another device first.  A one-device mesh is a
+                # one-chip replica on that device.  Wider meshes run the
+                # decode step under shard_map so the paged-attention
+                # kernel grid sees only local heads; the model body
+                # psums the logits.  Prefill / scatter / COW stay plain
+                # jit: GSPMD reads the same sharded buffers, and
+                # exactness is the model's contract (integer ToyLM:
+                # bit-exact; transformers: per-head attention is
+                # untouched, only the wo psum reorders float adds).
                 from jax.sharding import PartitionSpec
-                from repro.compat import shard_map
                 from repro.parallel.sharding import serving_tp_shardings
                 pspecs = model.tp_param_specs(self.params)
-                poolspecs = model.tp_pool_specs(store)
+                poolspecs = model.tp_pool_specs(jax.eval_shape(init_store))
                 self.params = jax.device_put(
                     self.params, serving_tp_shardings(self.mesh, pspecs)
                 )
-                store = jax.device_put(
-                    store, serving_tp_shardings(self.mesh, poolspecs)
-                )
-                rep = PartitionSpec()
-                self._decode_paged = jax.jit(
-                    shard_map(
-                        model.paged_decode_step, self.mesh,
-                        in_specs=(pspecs, poolspecs, rep, rep, rep),
-                        out_specs=(poolspecs, rep),
-                        check_vma=False,
-                    ),
-                    donate_argnums=(1,),
-                )
-            else:
-                self._decode_paged = jax.jit(
-                    model.paged_decode_step, donate_argnums=(1,)
-                )
+                store = jax.jit(
+                    init_store,
+                    out_shardings=serving_tp_shardings(self.mesh, poolspecs),
+                )()
+                if self.tp_shards > 1:
+                    rep = PartitionSpec()
+                    self._decode_paged = jax.jit(
+                        jax.shard_map(
+                            model.paged_decode_step, mesh=self.mesh,
+                            in_specs=(pspecs, poolspecs, rep, rep, rep),
+                            out_specs=(poolspecs, rep),
+                            check_vma=False,
+                        ),
+                        donate_argnums=(1,),
+                    )
             self.kv.bind_store(store)
             self._state = None
             self._prefill_rows = jax.jit(model.paged_prefill)

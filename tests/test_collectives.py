@@ -5,8 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.parallel.collectives import (
     ErrorFeedback,
     compressed_psum,
@@ -33,8 +31,8 @@ def test_compressed_psum_matches_exact():
     def fn(v):
         return compressed_psum(v, "pod")
 
-    out = shard_map(fn, mesh=mesh, in_specs=P(None, None),
-                    out_specs=P(None, None), check_vma=False)(x)
+    out = jax.shard_map(fn, mesh=mesh, in_specs=P(None, None),
+                        out_specs=P(None, None), check_vma=False)(x)
     # n=1: psum == identity up to quantization error
     np.testing.assert_allclose(np.asarray(out), np.asarray(x),
                                atol=float(jnp.abs(x).max()) / 120)

@@ -16,8 +16,7 @@ def test_loop_free_matches_xla():
 
     comp = _compile(f, jnp.ones((128, 128)), jnp.ones((128, 128)))
     mine = analyze_hlo(comp.as_text())
-    from repro.compat import cost_analysis
-    xla = cost_analysis(comp)["flops"]
+    xla = comp.cost_analysis()["flops"]
     assert abs(mine.flops - xla) / xla < 0.05
 
 

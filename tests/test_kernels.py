@@ -205,3 +205,30 @@ def test_segment_zero_sweep(n, lo, hi, dtype):
     out = segment_zero(x, lo, hi, interpret=True)
     ref = segment_zero_ref(x, lo, hi)
     assert jnp.array_equal(out, ref)
+
+
+# ------------------------------------------------------- interpret choice
+
+
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True)])
+def test_interpret_follows_backend(monkeypatch, backend, interpret):
+    from repro.kernels import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_interpret(None) is interpret
+    # an explicit choice wins over the backend
+    assert resolve_interpret(not interpret) is (not interpret)
+
+
+def test_interpret_refuses_unknown_backend(monkeypatch):
+    """A backend that is neither the TPU nor the CPU raises, so a kernel
+    never drops into the interpreter on a misconfigured machine."""
+    from repro.kernels import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        resolve_interpret(None)
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        paged_attention(jnp.zeros((1, 2, 16)), jnp.zeros((4, 8, 1, 16)),
+                        jnp.zeros((4, 8, 1, 16)), jnp.zeros((1, 1), jnp.int32),
+                        jnp.ones((1,), jnp.int32), scale=0.25)

@@ -238,7 +238,6 @@ def test_transformer_decode_step_sharded_bit_exact(n):
     a replicated-input matmul reduces the *same* partial products XLA
     would sum locally.  (Engine-level float divergence comes from GSPMD
     prefill reassociation, not the decode step — pinned exact here.)"""
-    from repro.compat import shard_map
     from repro.parallel.sharding import serving_tp_shardings
     from jax.sharding import PartitionSpec as P
 
@@ -265,8 +264,8 @@ def test_transformer_decode_step_sharded_bit_exact(n):
     sp = jax.device_put(params, serving_tp_shardings(mesh, pspecs))
     sstore = jax.device_put(store, serving_tp_shardings(mesh, poolspecs))
     rep = P()
-    fn = jax.jit(shard_map(
-        model.paged_decode_step, mesh,
+    fn = jax.jit(jax.shard_map(
+        model.paged_decode_step, mesh=mesh,
         in_specs=(pspecs, poolspecs, rep, rep, rep),
         out_specs=(poolspecs, rep), check_vma=False))
     sh_pool, sh_logits = fn(sp, sstore, tok, table, pos)
@@ -395,6 +394,23 @@ def test_replica_set_dp_times_tp():
     got, rs = _run_set(tp=2)
     assert got == base
     assert all(p["tp_shards"] == 2 for p in rs.replica_stats()["per_replica"])
+
+
+def test_one_device_replicas_each_on_own_device():
+    """4 one-device replicas: each replica's params and page pool live
+    on its own device (not all on the default one), and streams still
+    match the plain DP run."""
+    base, _ = _run_set()
+    got, rs = _run_set(dp=4, tp=1)
+    assert got == base
+    placed = []
+    for eng in rs.replicas:
+        assert eng.tp_shards == 1
+        on = {d for a in jax.tree.leaves((eng.params, eng.kv.store))
+              for d in a.devices()}
+        assert len(on) == 1
+        placed.append(on.pop())
+    assert len(set(placed)) == 4
 
 
 def test_kill_replica_rehomes_and_completes():

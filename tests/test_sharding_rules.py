@@ -17,13 +17,12 @@ from repro.parallel.sharding import (batch_shardings, decode_state_shardings,
 
 # NamedSharding.shard_shape only needs the mesh *shape*, not real devices:
 # an AbstractMesh stands in for the 256-chip pod.
+from jax.sharding import AbstractMesh  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
-
-from repro.compat import abstract_mesh  # noqa: E402
 
 
 def _mesh():
-    return abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
