@@ -678,6 +678,22 @@ class MetricsRegistry:
                 {"method": method},
             )
         fams.append(fam)
+        # host time inside the engine, by span: where a step's host
+        # share goes (admission, prefill dispatch, sampling, ...)
+        for key, name, text in (
+            ("span_seconds_total", "serving_span_seconds_total",
+             "Host seconds spent inside each serving-engine span."),
+            ("span_calls_total", "serving_span_calls_total",
+             "Times each serving-engine span was entered."),
+        ):
+            fam = _Family(self._n(name), "counter", text)
+            merged = {}
+            for s in stats:
+                for span, v in s.get(key, {}).items():
+                    merged[span] = merged.get(span, 0) + v
+            for span in sorted(merged):
+                fam.add(merged[span], {"span": span})
+            fams.append(fam)
         return fams
 
     def _replica_families(self, replica_sets: List[Any]) -> List[_Family]:

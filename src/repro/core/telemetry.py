@@ -9,6 +9,11 @@ or denied.  :class:`TelemetrySink` is that place: a bounded event log plus
 monotonic counters, shared by :mod:`~repro.core.admission`,
 :mod:`~repro.core.pool`, :class:`~repro.core.sandbox.Sandbox`,
 :class:`~repro.core.tasks.ServerlessScheduler` and the serving loop.
+
+:meth:`TelemetrySink.span` times a stretch of host code twice over: as a
+``jax.profiler.TraceAnnotation`` on the profiler's clock, beside the
+device's ops when a trace runs, and into always-on per-name totals of
+seconds and calls on ``time.perf_counter_ns``.
 """
 
 from __future__ import annotations
@@ -17,8 +22,11 @@ import threading
 import time
 from bisect import bisect_left
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "DEFAULT_BUCKETS",
@@ -151,6 +159,9 @@ class TelemetrySink:
         self._events: "deque[TelemetryEvent]" = deque(maxlen=capacity)
         self._counters: Dict[str, int] = {}
         self._histograms: Dict[Tuple[str, str], Histogram] = {}
+        #: span name -> [nanoseconds, calls]; the profiler keeps the
+        #: spans themselves while a trace runs, so no list is kept here
+        self._spans: Dict[str, List[int]] = {}
         self._lock = threading.Lock()
 
     # ----------------------------------------------------------------- emit
@@ -236,6 +247,26 @@ class TelemetrySink:
                 )
             hist.observe(value)
 
+    @contextmanager
+    def span(self, name: str, **meta: Any) -> Iterator[None]:
+        """Time the ``with`` body as span ``name``.
+
+        Opens a ``TraceAnnotation(name, **meta)`` (nearly free while no
+        trace runs) and, on exit, raising or not, adds the elapsed
+        ``perf_counter_ns`` to the name's totals.  Spans opened inside
+        the body are its children on the trace's timeline.
+        """
+        t0 = time.perf_counter_ns()
+        try:
+            with TraceAnnotation(name, **meta):
+                yield
+        finally:
+            elapsed = time.perf_counter_ns() - t0
+            with self._lock:
+                tot = self._spans.setdefault(name, [0, 0])
+                tot[0] += elapsed
+                tot[1] += 1
+
     # ---------------------------------------------------------------- query
 
     @property
@@ -267,6 +298,11 @@ class TelemetrySink:
             hist = self._histograms.get((name, tenant))
             return hist.copy() if hist is not None else None
 
+    def span_totals(self) -> Dict[str, Tuple[float, int]]:
+        """``{name: (seconds, calls)}`` of every span closed so far."""
+        with self._lock:
+            return {k: (ns / 1e9, n) for k, (ns, n) in self._spans.items()}
+
     def query(
         self,
         source: Optional[str] = None,
@@ -289,3 +325,4 @@ class TelemetrySink:
             self._events.clear()
             self._counters.clear()
             self._histograms.clear()
+            self._spans.clear()

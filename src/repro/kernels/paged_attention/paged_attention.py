@@ -141,5 +141,8 @@ def paged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KG, hd), q.dtype),
         interpret=interpret,
+        # the HLO instruction's name, which trace reductions match on;
+        # fixed here so that renaming this function cannot change it
+        name="paged_attention",
     )(page_table, lens, q, k_pages, v_pages)
     return out

@@ -56,6 +56,7 @@ serving loop to the engine.
 
 from __future__ import annotations
 
+import contextlib
 import heapq
 import itertools
 import threading
@@ -81,7 +82,15 @@ from repro.core.tasks import ServerlessScheduler, TaskSpec, TaskState, TenantQuo
 from repro.core.telemetry import TelemetrySink, resolve_sink
 from repro.runtime.sampling import sample_token
 
-__all__ = ["Request", "ServerConfig", "Server", "ServingEngine"]
+__all__ = ["SPANS", "Request", "ServerConfig", "Server", "ServingEngine"]
+
+#: the engine's spans (``TelemetrySink.span``): ``serve.submit`` and
+#: ``serve.step``, and inside each step, in order, its children
+SPANS = (
+    "serve.submit", "serve.step", "serve.admit", "serve.prefill",
+    "serve.arena_sample", "serve.table", "serve.decode", "serve.fetch",
+    "serve.sample", "serve.retire",
+)
 
 
 @dataclass
@@ -579,6 +588,10 @@ class ServingEngine:
         slots, or when the request can never fit: one oversized request
         must fail alone, not crash the shared decode plane mid-batch.
         """
+        with self.telemetry.span("serve.submit", req=r.request_id):
+            return self._submit(r)
+
+    def _submit(self, r: Request) -> int:
         with self._lock:
             if r.arrived_at is None:
                 r.arrived_at = self._exec.now()
@@ -910,7 +923,9 @@ class ServingEngine:
             n = min(budget, len(r.prompt) + len(r.tokens) - p)
             if n <= 0:
                 continue
-            chunk_fn(slot, r, p, n)
+            with self.telemetry.span("serve.prefill", req=r.request_id,
+                                     tokens=n, shared=p):
+                chunk_fn(slot, r, p, n)
             budget -= n
             worked = True
         return worked
@@ -1068,18 +1083,27 @@ class ServingEngine:
         """One engine tick: validate arena, admit, decode once, retire.
 
         Returns the number of requests retired this tick.  Safe to call
-        with nothing active (returns 0 after the admit sweep).
+        with nothing active (returns 0 after the admit sweep).  The tick
+        runs inside span ``serve.step``, each phase inside its own child
+        span (``SPANS``).
         """
+        with self.telemetry.span("serve.step", step=self._decode_steps):
+            return self._step()
+
+    def _step(self) -> int:
         if self.dead:
             return 0
-        self._evict_poisoned()
-        with self._lock:
-            admitted = self._admit_locked()
+        span = self.telemetry.span
+        with span("serve.admit"):
+            self._evict_poisoned()
+            with self._lock:
+                admitted = self._admit_locked()
         if self._chunked:
             # chunked prefill pumps every tick (not just on admission):
             # a prompt larger than one budget finishes over several steps
             if self._pump_prefill_chunks():
-                self.kv.arena.mm.host_vma_count()
+                with span("serve.arena_sample"):
+                    self.kv.arena.mm.host_vma_count()
         elif admitted:
             if self.cfg.incremental:
                 prefill = (
@@ -1088,14 +1112,21 @@ class ServingEngine:
                 )
                 for slot, r, need, start in admitted:
                     if need:
-                        prefill(slot, r, start)
+                        with span(
+                            "serve.prefill", req=r.request_id,
+                            tokens=len(r.prompt) + len(r.tokens) - start,
+                            shared=start,
+                        ):
+                            prefill(slot, r, start)
             else:
-                self._prefill_full()
+                with span("serve.prefill"):
+                    self._prefill_full()
             # sample arena occupancy while sequences are live (lazy
             # host-VMA tracking only updates on poll)
-            self.kv.arena.mm.host_vma_count()
+            with span("serve.arena_sample"):
+                self.kv.arena.mm.host_vma_count()
         paged = self.kv_mode == "paged"
-        with self._lock:
+        with span("serve.table"), self._lock:
             # PREFILLING slots are not live: they join the decode batch
             # only once their prompt is fully resident
             live = [
@@ -1142,83 +1173,103 @@ class ServingEngine:
         if not live:
             return 0
 
-        last = np.zeros((self.cfg.max_batch,), np.int32)
-        for i, r in live:
-            last[i] = r.tokens[-1] if r.tokens else int(r.prompt[-1])
-        if paged:
-            store, logits = self._decode_paged(
-                self.params, self.kv.store, jnp.asarray(last),
-                jnp.asarray(table), jnp.asarray(pos),
-            )
-            self.kv.swap_store(store)
-        else:
-            self._state, logits = self._decode(
-                self.params, self._state, jnp.asarray(last)
-            )
-        logits_np = np.asarray(logits)
-
-        retiring: List[Request] = []
-        now_t = self._exec.now()
-        with self._lock:
-            self._decode_steps += 1
+        with span("serve.decode"):
+            last = np.zeros((self.cfg.max_batch,), np.int32)
             for i, r in live:
-                if self._slots[i] is not r:
-                    continue               # evicted mid-step by chaos
-                tok, method = sample_token(
-                    logits_np[i],
-                    temperature=r.temperature, top_k=r.top_k,
-                    top_p=r.top_p, seed=r.seed, index=len(r.tokens),
+                last[i] = r.tokens[-1] if r.tokens else int(r.prompt[-1])
+            if paged:
+                store, logits = self._decode_paged(
+                    self.params, self.kv.store, jnp.asarray(last),
+                    jnp.asarray(table), jnp.asarray(pos),
                 )
-                self._sampled[method] += 1
-                r.tokens.append(tok)
-                if len(r.tokens) == 1:
-                    # first sampled token ever for this request (token
-                    # streams survive evictions, so this fires once):
-                    # time-to-first-token from *arrival* — admit wait,
-                    # queueing and the whole prefill are all inside it
-                    self.telemetry.observe(
-                        "serving.ttft_seconds", now_t - r.arrived_at,
-                        tenant=r.tenant,
-                    )
-                else:
-                    prev = self._last_tok_t.get(r.request_id)
-                    if prev is not None:
-                        # inter-token stall: gaps include any eviction
-                        # outage or prefill-induced stall between ticks
-                        self.telemetry.observe(
-                            "serving.intertoken_seconds", now_t - prev,
-                            tenant=r.tenant,
-                        )
-                self._last_tok_t[r.request_id] = now_t
-                if paged:
-                    # the row was reserved pre-step; make the count stick
-                    self.kv.ensure_tokens(
-                        self._seq_id(r), len(r.prompt) + len(r.tokens)
-                    )
-                else:
-                    self.kv.append_tokens(self._seq_id(r), 1)
-                self._tokens_n[r.tenant] = self._tokens_n.get(r.tenant, 0) + 1
-                if len(r.tokens) >= r.max_new_tokens:
-                    # release the KV pages and the slot *before* any user
-                    # post-code runs: a failing post-processor can never
-                    # leak them, and the slot is immediately reusable
-                    r.done = True
-                    if not self._park_locked(r):
-                        self.kv.drop_sequence(self._seq_id(r))
-                    self.admission.slot_released(r.tenant)
-                    self._slots[i] = None
-                    self._last_tok_t.pop(r.request_id, None)
-                    self._note("retire", r, f"slot={i}")
-                    retiring.append(r)
-        for r in retiring:
-            # postprocess outside the engine lock: user code must never
-            # gate submit(), metrics scrapes or the chaos watchdogs
-            self._postprocess(r)
+                self.kv.swap_store(store)
+            else:
+                self._state, logits = self._decode(
+                    self.params, self._state, jnp.asarray(last)
+                )
+        with span("serve.fetch"):
+            logits_np = np.asarray(logits)
+
+        now_t = self._exec.now()
+        # serve.retire opens under the lock, right after sampling, and
+        # closes once the retired requests are post-processed outside it
+        with contextlib.ExitStack() as retire_span:
             with self._lock:
-                self._finish_locked(r)
+                with span("serve.sample"):
+                    retiring = self._sample_locked(live, logits_np, now_t)
+                retire_span.enter_context(span("serve.retire"))
+                self._retire_locked(retiring)
+            for _, r in retiring:
+                # postprocess outside the engine lock: user code must never
+                # gate submit(), metrics scrapes or the chaos watchdogs
+                self._postprocess(r)
+                with self._lock:
+                    self._finish_locked(r)
         if retiring:
             self._exec.notify()
         return len(retiring)
+
+    def _sample_locked(
+        self, live: List[Tuple[int, Request]], logits_np: np.ndarray,
+        now_t: float,
+    ) -> List[Tuple[int, Request]]:
+        """Sample one token for each live row; returns the (slot,
+        request) pairs that reached ``max_new_tokens``."""
+        finished: List[Tuple[int, Request]] = []
+        self._decode_steps += 1
+        for i, r in live:
+            if self._slots[i] is not r:
+                continue               # evicted mid-step by chaos
+            tok, method = sample_token(
+                logits_np[i],
+                temperature=r.temperature, top_k=r.top_k,
+                top_p=r.top_p, seed=r.seed, index=len(r.tokens),
+            )
+            self._sampled[method] += 1
+            r.tokens.append(tok)
+            if len(r.tokens) == 1:
+                # first sampled token ever for this request (token
+                # streams survive evictions, so this fires once):
+                # time-to-first-token from *arrival* — admit wait,
+                # queueing and the whole prefill are all inside it
+                self.telemetry.observe(
+                    "serving.ttft_seconds", now_t - r.arrived_at,
+                    tenant=r.tenant,
+                )
+            else:
+                prev = self._last_tok_t.get(r.request_id)
+                if prev is not None:
+                    # inter-token stall: gaps include any eviction
+                    # outage or prefill-induced stall between ticks
+                    self.telemetry.observe(
+                        "serving.intertoken_seconds", now_t - prev,
+                        tenant=r.tenant,
+                    )
+            self._last_tok_t[r.request_id] = now_t
+            if self.kv_mode == "paged":
+                # the row was reserved pre-step; make the count stick
+                self.kv.ensure_tokens(
+                    self._seq_id(r), len(r.prompt) + len(r.tokens)
+                )
+            else:
+                self.kv.append_tokens(self._seq_id(r), 1)
+            self._tokens_n[r.tenant] = self._tokens_n.get(r.tenant, 0) + 1
+            if len(r.tokens) >= r.max_new_tokens:
+                finished.append((i, r))
+        return finished
+
+    def _retire_locked(self, retiring: List[Tuple[int, Request]]) -> None:
+        """Release the KV pages and the slot of each finished request
+        *before* any user post-code runs: a failing post-processor can
+        never leak them, and the slot is immediately reusable."""
+        for i, r in retiring:
+            r.done = True
+            if not self._park_locked(r):
+                self.kv.drop_sequence(self._seq_id(r))
+            self.admission.slot_released(r.tenant)
+            self._slots[i] = None
+            self._last_tok_t.pop(r.request_id, None)
+            self._note("retire", r, f"slot={i}")
 
     def _park_locked(self, r: Request) -> bool:
         """Park a retiring request's sequence as a prefix-cache donor.
@@ -1611,7 +1662,13 @@ class ServingEngine:
             return sum(self._queue_depths_locked().values())
 
     def serving_stats(self) -> Dict[str, Any]:
-        """Snapshot consumed by ``MetricsRegistry.register_serving``."""
+        """Snapshot consumed by ``MetricsRegistry.register_serving``.
+
+        ``span_seconds_total`` / ``span_calls_total`` hold the host time
+        and calls of each of the engine's spans (``SPANS``), read from
+        its telemetry sink.
+        """
+        spans = self.telemetry.span_totals()
         with self._lock:
             queue = self._queue_depths_locked()
             return {
@@ -1639,6 +1696,12 @@ class ServingEngine:
                 "prefix_shared_pages_total": self.kv.shared_pages_total,
                 "prefix_cow_copies_total": self.kv.cow_copies_total,
                 "prefix_prefill_tokens_saved_total": self._prefix_tokens_saved,
+                "span_seconds_total": {
+                    k: spans.get(k, (0.0, 0))[0] for k in SPANS
+                },
+                "span_calls_total": {
+                    k: spans.get(k, (0.0, 0))[1] for k in SPANS
+                },
             }
 
     def admit_wait_snapshot(self) -> Tuple[float, float]:
