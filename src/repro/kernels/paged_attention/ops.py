@@ -32,12 +32,14 @@ def paged_attention_sharded(q, k_pages, v_pages, page_table, lens, *,
                             interpret: bool | None = None):
     """Head-sharded paged attention over a tensor-parallel mesh.
 
-    Each mesh member runs the kernel grid over its KV-head slice of the
-    page pool (q heads are KV-major, so the matching q slice is
-    contiguous); outputs concatenate back over the head axis.  Per-KV-head
-    online softmax is independent, so the sharded result is bit-identical
-    to the unsharded kernel.  When the head counts don't divide the mesh
-    — or there is no mesh — falls back to the unsharded kernel on
+    Each mesh member runs the kernel over its KV-head slice of the page
+    pool (q heads are KV-major, so the matching q slice is contiguous);
+    outputs concatenate back over the head axis.  The kernel computes each
+    KV head on its own, so the sharded result is bit-identical to the
+    unsharded kernel wherever the slice and the whole choose the same
+    block (``pages_per_block``: they differ only where a block of the
+    whole's K passes ``BLOCK_BYTES``).  When the head counts don't divide
+    the mesh — or there is no mesh — falls back to the unsharded kernel on
     replicated inputs rather than mis-slicing a head group.
     """
     num_kv = k_pages.shape[2]

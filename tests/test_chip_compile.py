@@ -94,6 +94,21 @@ def test_paged_kernel_compiles_at_qwen_widths(one_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def test_paged_kernel_compiles_at_starcoder_widths(one_chip):
+    """starcoder2-7b's heads (4 KV heads of 9 queries each) over its
+    7168-page pool, 32 rows of a 256-page table: the widest bucket the
+    engine pads to, at the largest block the kernel chooses."""
+    pages = _shape((7168, PAGE, 4, 128), jnp.bfloat16, one_chip)
+    compiled = paged_attention_pallas.lower(
+        _shape((32, 36, 128), jnp.bfloat16, one_chip), pages, pages,
+        _shape((32, 256), jnp.int32, one_chip),
+        _shape((32,), jnp.int32, one_chip),
+        scale=128 ** -0.5, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 def test_sharded_kernel_compiles_over_four_chips(topo):
     mesh = Mesh(np.asarray(topo.devices), ("model",))
     rep = NamedSharding(mesh, PartitionSpec())

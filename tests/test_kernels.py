@@ -8,6 +8,7 @@ import pytest
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.paged_attention.ops import paged_attention
+from repro.kernels.paged_attention.paged_attention import pages_per_block
 from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.kernels.segment_zero.ops import segment_zero
 from repro.kernels.segment_zero.ref import segment_zero_ref
@@ -158,6 +159,42 @@ def test_paged_attention_dead_rows_and_padded_tables():
     np.testing.assert_allclose(out[[0, 2]],
                                np.asarray(narrow, np.float32)[[0, 2]],
                                rtol=0, atol=0)
+
+
+@pytest.mark.parametrize("width", [1, 3, 64, 256])
+@pytest.mark.parametrize("K,G", [(8, 5), (4, 9)])     # qwen2.5-32b, starcoder2-7b
+def test_paged_attention_block_edges(K, G, width):
+    """The served head shapes (hd 128, 16-token pages, bf16) at table
+    widths from one page to 256, with rows that end exactly on a block
+    boundary, one token past it, inside their first page, and fill the
+    table, beside an empty slot as the engine passes it (an all--1 row
+    with a nonzero length); widths 1 and 3 lie below the block size the
+    shapes choose."""
+    page, hd = 16, 128
+    block = pages_per_block(page, K, hd, 2, width) * page
+    cap = width * page
+    lens = [n for n in (block, block + 1, 5, cap) if n <= cap] + [1]
+    B = len(lens)
+    live = np.arange(B) < B - 1
+    q = jnp.asarray(RNG.standard_normal((B, K * G, hd)), jnp.bfloat16)
+    P = B * width
+    kp = jnp.asarray(RNG.standard_normal((P, page, K, hd)), jnp.bfloat16)
+    vp = jnp.asarray(RNG.standard_normal((P, page, K, hd)), jnp.bfloat16)
+    table = np.full((B, width), -1, np.int32)
+    pool = list(RNG.permutation(P))
+    for b, n in enumerate(lens[:-1]):
+        for i in range(-(-n // page)):
+            table[b, i] = pool.pop()
+    lens = np.asarray(lens, np.int32)
+    out = np.asarray(paged_attention(q, kp, vp, table, lens, scale=hd ** -0.5,
+                                     interpret=True), np.float32)
+    assert np.all(out[~live] == 0.0)
+    ref = paged_attention_ref(q, kp, vp, jnp.asarray(table),
+                              jnp.asarray(lens), scale=hd ** -0.5)
+    np.testing.assert_allclose(out[live], np.asarray(ref, np.float32)[live],
+                               **_tol(jnp.bfloat16))
+    brute = _paged_brute_force(q, kp, vp, table, lens, hd ** -0.5)
+    np.testing.assert_allclose(out[live], brute[live], **_tol(jnp.bfloat16))
 
 
 @pytest.mark.parametrize("B,T,H,hd", [(1, 32, 1, 8), (2, 128, 3, 16)])
