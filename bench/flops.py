@@ -3,6 +3,9 @@
 Counts are what the algorithm needs, not what the program happens to
 compute: causal attention counts each query against the keys at or
 before it, and the output head counts the rows whose logits are used.
+One layer's matrix work per token is the configuration's family's
+(``matmul_params_per_token``); attention, the head and the paged
+kernel's traffic are the same for every family.
 """
 
 from __future__ import annotations
@@ -10,15 +13,8 @@ from __future__ import annotations
 import math
 from typing import Iterable
 
+from bench import config
 from bench.config import Shapes
-
-
-def matmul_params_per_layer(s: Shapes) -> int:
-    q = s.heads * s.head_dim
-    kv = s.kv_heads * s.head_dim
-    attn = s.d_model * (q + 2 * kv) + q * s.d_model
-    mlp = (3 if s.gated else 2) * s.d_model * s.d_ff
-    return attn + mlp
 
 
 def head_flops(s: Shapes, rows: int) -> int:
@@ -34,7 +30,7 @@ def attention_flops(s: Shapes, context: int) -> int:
 def prefill_flops(s: Shapes, start: int, n: int) -> int:
     """``n`` tokens at positions ``[start, start + n)`` with the rows
     before ``start`` already cached; logits for the last token only."""
-    dense = 2 * n * s.layers * matmul_params_per_layer(s)
+    dense = 2 * n * s.layers * config.family(s).matmul_params_per_token(s)
     # sum of contexts start+1 .. start+n
     contexts = n * start + n * (n + 1) // 2
     return dense + attention_flops(s, 1) * contexts + head_flops(s, 1)
@@ -44,7 +40,7 @@ def decode_flops(s: Shapes, lens: Iterable[int]) -> int:
     """One decode step: one token per row, row i attending ``lens[i]``
     keys (its own included), with the head over every row."""
     lens = list(lens)
-    per_row = 2 * s.layers * matmul_params_per_layer(s)
+    per_row = 2 * s.layers * config.family(s).matmul_params_per_token(s)
     return (len(lens) * per_row + sum(attention_flops(s, n) for n in lens)
             + head_flops(s, len(lens)))
 

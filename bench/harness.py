@@ -4,7 +4,9 @@ correctness check and the result line.
 Everything that belongs to a configuration, a traffic mix or a
 per-layer metric is found by the name ``BENCHMARK.json`` gives it:
 ``configs/<config>.json``, ``mixes/<traffic>.json`` and
-``metrics/<metric>.py``.  This file names none of them.
+``metrics/<metric>.py``, and a configuration's model family by the name
+its file gives it, ``families/<family>.py``.  This file names none of
+them.
 
 The window drives the program's ``Server.submit`` / ``Server.step``.
 The harness owns the loop: before each ``step()`` it submits every
@@ -155,23 +157,12 @@ class CompileCounter:
 # --------------------------------------------------------------- program
 
 
-def arch_config(s):
-    """The program's config for these shapes."""
-    from repro.models.common import ArchConfig
-    return ArchConfig(
-        arch_id=s.name, family="dense", num_layers=s.layers,
-        d_model=s.d_model, num_heads=s.heads, num_kv_heads=s.kv_heads,
-        head_dim=s.head_dim, d_ff=s.d_ff, vocab_size=s.vocab,
-        qkv_bias=s.qkv_bias, rope_base=s.rope_theta, norm_eps=s.norm_eps,
-        activation=s.activation, gated=s.gated,
-        tie_embeddings=s.tie_embeddings, dtype=s.dtype)
-
-
 def build_server(s, mix: dict, params):
     from repro.models import build_model
     from repro.runtime import Server, ServerConfig
     import jax
-    model = build_model(arch_config(s))
+    from bench import config
+    model = build_model(config.family(s).arch_config(s))
     want = jax.tree.map(lambda a: (a.shape, a.dtype),
                         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
@@ -625,7 +616,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         log(f"compile cache {enable_compile_cache()}")
     else:
         devices = jax.devices()[:cell.chips]
-    s = config.shapes_from(cell.raw)
+    s = config.shapes_from(cell.raw, root)
     counter = CompileCounter()
     tr = traffic_mod.generate(cell.mix, seed, s.vocab, seconds)
     from bench import weights

@@ -1,10 +1,12 @@
 """Plain reference forward and the comparison that decides ``correct``.
 
 The reference is the published decoder written out in ``jax.numpy`` in
-float32 at ``precision="highest"``: RMSNorm, q/k/v with their biases,
-rotary embedding on the two halves of each head, grouped-query causal
-attention, the MLP (SwiGLU, or the plain two-matrix GELU-tanh MLP), a
-final norm and an untied head.  It takes the weights that
+float32 at ``precision="highest"``: the embedding, the configuration's
+family's ``layer`` (``bench/families/``) scanned over the layers, a
+final RMSNorm and the head.  For the dense family a layer is RMSNorm,
+q/k/v with their biases, rotary embedding on the two halves of each
+head, grouped-query causal attention and the MLP (SwiGLU, or the plain
+two-matrix GELU-tanh MLP).  It takes the weights that
 ``bench.weights.make`` draws again from the seed, never the program's,
 and imports nothing of the program.  Attention runs in blocks of
 queries and the head only at the served positions, so one stream of
@@ -24,13 +26,13 @@ the fp8 forward puts first.
 from __future__ import annotations
 
 import functools
-import math
 from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import config
 from bench.config import Shapes
 
 QUERY_BLOCK = 512
@@ -73,59 +75,16 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _act(name: str):
-    if name == "silu":
-        return jax.nn.silu
-    if name == "gelu_tanh":
-        return functools.partial(jax.nn.gelu, approximate=True)
-    raise ValueError(f"activation {name!r}")
-
-
-def _layer(s: Shapes, lower: bool, x, p):
-    S = x.shape[0]
-    K, G, hd = s.kv_heads, s.heads // s.kv_heads, s.head_dim
-    pos = jnp.arange(S)
-    a = _rms(x, p["ln1"], s.norm_eps)
-    q = _mm("sd,dhk->shk", a, p["attn"]["wq"], lower)
-    k = _mm("sd,dhk->shk", a, p["attn"]["wk"], lower)
-    v = _mm("sd,dhk->shk", a, p["attn"]["wv"], lower)
-    if s.qkv_bias:
-        q = q + p["attn"]["bq"].astype(jnp.float32)
-        k = k + p["attn"]["bk"].astype(jnp.float32)
-        v = v + p["attn"]["bv"].astype(jnp.float32)
-    q = _rope(q, pos, s.rope_theta) / math.sqrt(hd)
-    k = _rope(k, pos, s.rope_theta)
-    qb = q.reshape(S // QUERY_BLOCK, QUERY_BLOCK, K, G, hd)
-
-    def block(args):
-        i, qi = args
-        sc = _mm("qkgh,tkh->kgqt", qi, k, lower)
-        rows = i * QUERY_BLOCK + jnp.arange(QUERY_BLOCK)
-        sc = jnp.where(pos[None, :] <= rows[:, None], sc, -jnp.inf)
-        w = jax.nn.softmax(sc, axis=-1)
-        return _mm("kgqt,tkh->qkgh", w, v, lower)
-
-    o = jax.lax.map(block, (jnp.arange(S // QUERY_BLOCK), qb))
-    o = o.reshape(S, s.heads * hd)
-    x = x + _mm("sf,fd->sd", o, p["attn"]["wo"], lower)
-    m = _rms(x, p["ln2"], s.norm_eps)
-    up = _mm("sd,df->sf", m, p["mlp"]["wu"], lower)
-    if s.gated:
-        h = _act(s.activation)(_mm("sd,df->sf", m, p["mlp"]["wg"], lower)) * up
-    else:
-        h = _act(s.activation)(up)
-    return x + _mm("sf,fd->sd", h, p["mlp"]["wd"], lower)
-
-
 @functools.partial(jax.jit, static_argnames=("s", "lower"))
 def hidden(weights, tokens, *, s: Shapes, lower: bool = False):
     """Final normed hidden state of every position, float32; ``tokens``
     length a multiple of ``QUERY_BLOCK`` (padding at the end is causal
     and harms no earlier position)."""
     x = weights["embed"][tokens].astype(jnp.float32)
+    layer = config.family(s).layer
 
     def body(x, p):
-        return _layer(s, lower, x, p), None
+        return layer(s, lower, x, p), None
 
     x, _ = jax.lax.scan(body, x, weights["layers"])
     return _rms(x, weights["final_norm"], s.norm_eps)

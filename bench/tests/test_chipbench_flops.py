@@ -1,6 +1,7 @@
-"""bench/flops.py against counts worked out by hand."""
+"""bench/flops.py, through the dense family, against counts worked out
+by hand."""
 
-from bench import flops
+from bench import config, flops
 from bench.config import Shapes
 
 
@@ -9,15 +10,17 @@ def tiny(**kw) -> Shapes:
                 head_dim=2, d_ff=16, vocab=10, rope_theta=1e4, norm_eps=1e-5,
                 gated=True, activation="silu", qkv_bias=True,
                 tie_embeddings=False, dtype="bfloat16", max_batch=4,
-                max_seq=64, page_tokens=4, pool_pages=32)
+                max_seq=64, page_tokens=4, pool_pages=32,
+                family=str(config.family_file("dense")))
     base.update(kw)
     return Shapes(**base)
 
 
 def test_matmul_params_per_layer():
     # q 8x8, k 8x4, v 8x4, o 8x8, gated mlp 3 x 8x16
-    assert flops.matmul_params_per_layer(tiny()) == 64 + 32 + 32 + 64 + 384
-    assert flops.matmul_params_per_layer(tiny(gated=False)) == 192 + 256
+    per_token = config.family(tiny()).matmul_params_per_token
+    assert per_token(tiny()) == 64 + 32 + 32 + 64 + 384
+    assert per_token(tiny(gated=False)) == 192 + 256
 
 
 def test_decode_flops_by_hand():

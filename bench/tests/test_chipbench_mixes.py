@@ -122,7 +122,7 @@ BURSTY = {
 
 def test_a_new_mix_file_is_found_by_name(tmp_path):
     root = tmp_path
-    for sub in ("configs", "mixes", "limits"):
+    for sub in ("configs", "mixes", "limits", "families"):
         shutil.copytree(BENCH / sub, root / "bench" / sub)
     spec = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
     (root / "bench" / "mixes" / "bursty.json").write_text(json.dumps(BURSTY))
@@ -148,5 +148,5 @@ def test_a_new_mix_file_is_found_by_name(tmp_path):
     other = traffic.generate(cell.mix, 5, 1000, 50)
     assert {it.turns[0].user for it in other.items} != set(prompts)
     from bench import config
-    keys, _ = harness.warm_plan(t, config.shapes_from(cell.raw))
+    keys, _ = harness.warm_plan(t, config.shapes_from(cell.raw, root))
     assert {k[1] for k in keys} == set(prompts)

@@ -4,7 +4,7 @@ small size on the CPU, and the consumed stream it compares over."""
 import numpy as np
 import pytest
 
-from bench import harness, reference, weights
+from bench import config, reference, weights
 from bench.config import Shapes
 
 
@@ -14,7 +14,8 @@ def smoke(dtype="float32", gated=True) -> Shapes:
                   norm_eps=1e-5, gated=gated,
                   activation="silu" if gated else "gelu_tanh", qkv_bias=True,
                   tie_embeddings=False, dtype=dtype, max_batch=4,
-                  max_seq=256, page_tokens=16, pool_pages=64)
+                  max_seq=256, page_tokens=16, pool_pages=64,
+                  family=str(config.family_file("dense")))
 
 
 @pytest.mark.parametrize("gated", [True, False])
@@ -23,7 +24,7 @@ def test_reference_matches_program_forward_in_f32(gated):
     from repro.models import build_model
     s = smoke(gated=gated)
     w = weights.make(s, 2**31 + 5)
-    model = build_model(harness.arch_config(s))
+    model = build_model(config.family(s).arch_config(s))
     toks = np.random.default_rng(0).integers(0, s.vocab, 40).astype(np.int32)
     logits, _ = model.forward(w, jnp.asarray(toks[None]))
     padded = np.zeros(reference.QUERY_BLOCK, np.int32)

@@ -6,6 +6,7 @@ without a TPU."""
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -22,8 +23,9 @@ SMOKE = {
     "num_key_value_heads": 2, "num_hidden_layers": 2, "vocab_size": 8192,
     "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
     "tie_word_embeddings": False, "torch_dtype": "bfloat16",
-    "arch": {"mlp": "gated", "activation": "silu", "qkv_bias": True,
-             "out_bias": False, "mlp_bias": False, "norm": "rms"},
+    "arch": {"family": "dense", "mlp": "gated", "activation": "silu",
+             "qkv_bias": True, "out_bias": False, "mlp_bias": False,
+             "norm": "rms"},
     "serving": {"max_batch": 4, "max_seq": 256, "page_tokens": 16,
                 "pool_pages": 256},
 }
@@ -49,6 +51,8 @@ def root(tmp_path_factory):
     root = tmp_path_factory.mktemp("smoke")
     for sub in ("configs", "mixes", "limits"):
         (root / "bench" / sub).mkdir(parents=True)
+    shutil.copytree(harness.BENCH / "families", root / "bench" / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (root / "bench/configs/smoke.json").write_text(json.dumps(SMOKE))
     (root / "bench/mixes/chat.json").write_text(json.dumps(CHAT))
     (root / f"bench/limits/{CELL}.json").write_text(

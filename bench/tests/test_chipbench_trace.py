@@ -86,3 +86,34 @@ def test_recorded_trace_busy_is_the_modules_time(recorded):
     assert recorded["busy_s"] == pytest.approx(modules, rel=0.05)
     # the loop around each step's layers is not a leaf op
     assert not any(n.startswith("while") for n, _ in recorded["top_ops"])
+
+
+def test_idle_gaps_take_the_innermost_serve_span():
+    import jax
+    # the small trace with the program's spans inside bench.step:
+    # serve.step [2500,7800) and its child serve.fetch [4800,7500); the
+    # idle [5000,7000) lies in both, [1000,2000) and [8000,10500) in
+    # bench spans only
+    text = (DATA / "small_trace.pbtxt").read_text()
+    text = text.replace(
+        '    events { metadata_id: 4 offset_ps: 8000000 duration_ps: 3000000 }\n',
+        '    events { metadata_id: 4 offset_ps: 8000000 duration_ps: 3000000 }\n'
+        '    events { metadata_id: 5 offset_ps: 2500000 duration_ps: 5300000 }\n'
+        '    events { metadata_id: 6 offset_ps: 4800000 duration_ps: 2700000 }\n')
+    text = text.replace(
+        '  event_metadata { key: 4 value { id: 4 name: "bench.wait" } }\n',
+        '  event_metadata { key: 4 value { id: 4 name: "bench.wait" } }\n'
+        '  event_metadata { key: 5 value { id: 5 name: "serve.step" } }\n'
+        '  event_metadata { key: 6 value { id: 6 name: "serve.fetch" } }\n')
+    red = trace.reduce_profile(jax.profiler.ProfileData.from_text_proto(text))
+    gaps = dict((round(s * 1e9), label) for label, s in red["idle_gaps"])
+    assert gaps == {1000: "bench.submit", 2000: "serve.fetch",
+                    2500: "bench.wait"}
+
+
+def test_recorded_trace_without_serve_spans_keeps_bench_labels(recorded):
+    # recorded before the program had spans: every gap inside the window
+    # lies in a step, and keeps that label
+    labels = {label for label, _ in recorded["idle_gaps"]}
+    assert labels == {"bench.step"}
+    assert len(recorded["idle_gaps"]) == trace.TOP

@@ -15,7 +15,8 @@ between two engine steps.  Inside it, on each device plane:
 * kernel time: ops whose HLO instruction is the paged-attention Pallas
   kernel (``%paged_attention_pallas.N = ... custom-call(...)``);
 * idle gaps: the stretches between busy intervals, each labelled by the
-  innermost ``bench.*`` host span around its middle.
+  innermost of the program's ``serve.*`` host spans around its middle,
+  or where there is none, the innermost of the benchmark's ``bench.*``.
 """
 
 from __future__ import annotations
@@ -79,7 +80,7 @@ def reduce_profile(pd) -> dict:
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name.startswith("bench."):
+                if ev.name.startswith(("bench.", "serve.")):
                     a = int(ev.start_ns)
                     b = a + int(ev.duration_ns)
                     if ev.name == WINDOW_SPAN:
@@ -146,12 +147,16 @@ def reduce_profile(pd) -> dict:
 
 
 def _label(spans: List[Tuple[int, int, str]], t: int) -> str:
-    """The innermost host span of the benchmark around time ``t``."""
+    """The innermost ``serve.*`` host span around time ``t``, else the
+    innermost ``bench.*`` one."""
     best = None
     for a, b, name in spans:
-        if a <= t < b and (best is None or b - a < best[1] - best[0]):
-            best = (a, b, name)
-    return best[2] if best else "outside bench spans"
+        if not a <= t < b:
+            continue
+        key = (not name.startswith("serve."), b - a)
+        if best is None or key < best[0]:
+            best = (key, name)
+    return best[1] if best else "outside bench spans"
 
 
 def reduce(trace_dir, window_record: dict) -> dict:
